@@ -524,9 +524,7 @@ class WindowStager:
     Every kernel refresh stacks the dirty jobs' [N, R, S] windows into
     one [J, N, R, S] tensor, pads J to the next power of two (bounded
     jit shapes under elastic churn), and ships it to the device.  Done
-    naively that is a fresh `np.stack` allocation per tick; under buffer
-    donation the *device* copy is consumed by the kernel, so the host
-    staging array is the only piece that can be recycled.  The stager
+    naively that is a fresh `np.stack` allocation per tick.  The stager
     keeps one host buffer per padded shape and refills it in place —
     steady-state ticks allocate nothing on the host side.
 

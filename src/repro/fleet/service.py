@@ -319,12 +319,9 @@ class FleetService:
                     stacked = jax.device_put(stacked, self.device)
             with self._phase("tick.kernel"):
                 if use_fused:
-                    # one dispatch, one HBM read; the device input buffer
-                    # is donated — consumed by the kernel, never copied
-                    # back.
+                    # one dispatch, one HBM read of the staged windows
                     tick = fused_fleet_tick(
-                        stacked, sync_stages=sync_idx,
-                        with_regimes=False, donate=True,
+                        stacked, sync_stages=sync_idx, with_regimes=False,
                     )
                 else:
                     tick = four_dispatch_tick(

@@ -2,6 +2,7 @@
 
 frontier/ — fused frontier accounting (Eq. 2 shares + Eq. 4 gains + leader
 evidence in one HBM pass).  Each kernel ships <name>.py (pl.pallas_call +
-BlockSpec), ops.py (jitted wrapper, auto-interpret off-TPU) and ref.py
+BlockSpec), ops.py (jitted wrapper; interpret mode iff the backend is
+the CPU, see frontier.resolve_interpret) and ref.py
 (pure-jnp oracle swept by tests/test_kernel_frontier.py).
 """

@@ -34,9 +34,38 @@ NEG_INF = float("-inf")
 _BIG_IDX = 2**30  # python literal: becomes an immediate inside the kernel
 
 
+def resolve_interpret(interpret: bool | None) -> bool:
+    """The one interpret-mode rule for every Pallas route in this package.
+
+    None means: interpret iff JAX's default backend is the CPU.  On any
+    other backend the kernel is handed to the native compiler, and a
+    kernel it refuses raises there; nothing falls back to interpret mode.
+    """
+    if interpret is None:
+        return jax.default_backend() == "cpu"
+    return bool(interpret)
+
+
 def _merge_second(m1, s1, m2, s2):
     """Top-2 merge: second of the union of two (max, second) summaries."""
     return jnp.maximum(jnp.minimum(m1, m2), jnp.maximum(s1, s2))
+
+
+def _stage_prefix(x):
+    """Running sum over the stage (sublane, second-to-last) axis.
+
+    Unrolled in stage order, p[i] = p[i-1] + x[i]: the same sequential
+    fold as `np.cumsum`, so every route that shares this helper (and the
+    oracles) stays bit-exact.  Built from static one-row slices and
+    selects, which Mosaic lowers; it has no `cumsum`.
+    """
+    row = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 2)
+    run = x[..., 0:1, :]
+    out = jnp.broadcast_to(run, x.shape)
+    for i in range(1, x.shape[-2]):
+        run = run + x[..., i:i + 1, :]
+        out = jnp.where(row == i, run, out)
+    return out
 
 
 def _tile_reduce(d, b, j, *, r_total: int, r_tile: int, s_pad: int):
@@ -51,7 +80,7 @@ def _tile_reduce(d, b, j, *, r_total: int, r_tile: int, s_pad: int):
     valid = gidx < r_total
 
     # Prefix over stages (sublanes): short unrolled running sum.
-    prefix = jnp.cumsum(d, axis=0)               # [S_pad, R_TILE]
+    prefix = _stage_prefix(d)                    # [S_pad, R_TILE]
     prefix = jnp.where(valid, prefix, NEG_INF)
 
     # Tile-local frontier / leader (lowest global index on ties) / second.
@@ -124,7 +153,7 @@ def frontier_window_kernel(
     *,
     r_total: int | None = None,
     r_tile: int = 512,
-    interpret: bool = True,
+    interpret: bool,
 ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Run the fused kernel on stage-major input.
 
@@ -223,7 +252,7 @@ def _whatif_kernel(
     gidx = lane + j * r_tile
     valid = gidx < r_total
 
-    prefix = jnp.cumsum(w, axis=0)               # [S_pad, R_TILE]
+    prefix = _stage_prefix(w)                    # [S_pad, R_TILE]
     excess = jnp.maximum(0.0, w - b)             # [S_pad, R_TILE]
 
     # Replayed arrival of each lane at its stage's governing boundary —
@@ -335,7 +364,7 @@ def regime_stats_kernel(
     *,
     r_tile: int = 512,
     n_steps: int | None = None,
-    interpret: bool = True,
+    interpret: bool,
 ) -> tuple[jax.Array, ...]:
     """Batched regime statistics on stage-major excess streams.
 
@@ -393,7 +422,7 @@ def whatif_matrix_kernel(
     r_total: int | None = None,
     r_tile: int = 512,
     n_steps: int | None = None,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Candidate-batched counterfactual matrix on stage-major input.
 

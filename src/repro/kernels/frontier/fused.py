@@ -30,13 +30,15 @@ The fold-order rules that make this possible:
 
   * max / min / top-2-merge folds are order-independent exact, so the
     frontier family may fold across tiles in any grid order;
-  * float step sums are SEQUENTIAL adds in step order (`fori_loop`, no
-    `jnp.sum` reassociation, no multiply in the fold so nothing fuses to
-    an FMA) — identical to the unfused kernels' folds;
+  * float step sums are SEQUENTIAL adds in step order (a step loop
+    unrolled at trace time, no `jnp.sum` reassociation, no multiply in
+    the fold so nothing fuses to an FMA) — identical to the unfused
+    kernels' folds;
   * vectorizing the per-step tile math over a leading N axis is
-    elementwise-identical to the unfused per-step grid (cumsum / max /
-    where lower to the same per-element expression trees; asserted
-    bitwise by `tests/test_fused_tick.py` on every shape group);
+    elementwise-identical to the unfused per-step grid (the shared
+    `_stage_prefix` running sum, max and where give the same per-element
+    expression trees; asserted bitwise by `tests/test_fused_tick.py` on
+    every shape group);
   * all co-activation statistics are integer counts.
 
 `four_dispatch_tick` keeps the unfused composition callable as THE
@@ -55,7 +57,13 @@ from jax.experimental import pallas as pl
 
 from ...core.regimes import RegimeParams as _RegimeParams
 from ...core.whatif import sync_segments
-from .frontier import _BIG_IDX, NEG_INF, _merge_second
+from .frontier import (
+    _BIG_IDX,
+    NEG_INF,
+    _merge_second,
+    _stage_prefix,
+    resolve_interpret,
+)
 from .incidents import CoActivationPacket, co_activation, co_activation_ref
 from .ops import (
     FleetPacket,
@@ -64,7 +72,6 @@ from .ops import (
     _fleet_imputed_work,
     _fleet_median_baseline,
     _LANE,
-    _on_tpu,
     _pad_to,
     _SUBLANE,
     _whatif_stats,
@@ -79,6 +86,11 @@ __all__ = [
 ]
 
 _REGIME_DEFAULTS = _RegimeParams()
+
+#: steps x rank lanes of one window block that fit a TPU v5e's default
+#: scoped VMEM with every family on: rehearsal compiles fit N=100 at
+#: R_TILE=256 and run out at N=100, R_TILE=512 (tests/test_tpu_compile.py)
+_WINDOW_BLOCK_ELEMS = 100 * 256
 
 
 class FusedTickPacket(NamedTuple):
@@ -114,7 +126,7 @@ def _fused_tick_kernel(
     """One grid step = one (job, rank tile): every family from one load.
 
     Ref order (inputs): d, bd, w, bw window tiles [N, S_pad, R_TILE];
-    amax/second/leader/relprev what-if stats rows [N, S_pad]; then, when
+    amax/second/leader/relprev what-if stats rows [1, N, S_pad]; then, when
     enabled, thr [1, S_pad, R_TILE] and host one-hot [1, R_TILE, H_pad].
     Outputs: frontier family [1, N, S_pad] x4 (revisited across tiles),
     what-if [1, S_pad, R_TILE], the seven regime stats, and the
@@ -144,15 +156,14 @@ def _fused_tick_kernel(
     # -- frontier family: `_tile_reduce` vectorized over the step axis --
     d = d_ref[...].astype(jnp.float32)           # [N, S_pad, R_TILE]
     bd = bd_ref[...].astype(jnp.float32)
-    prefix_d = jnp.cumsum(d, axis=1)
-    prefix_d = jnp.where(valid[None], prefix_d, NEG_INF)
+    prefix_d = jnp.where(valid[None], _stage_prefix(d), NEG_INF)
     f_t = prefix_d.max(axis=2)                   # [N, S_pad]
     is_max = prefix_d == f_t[:, :, None]
     lead_t = jnp.where(is_max, gidx[None], _BIG_IDX).min(axis=2)
     masked = jnp.where(gidx[None] == lead_t[:, :, None], NEG_INF, prefix_d)
     sec_t = masked.max(axis=2)
     excess_d = jnp.maximum(0.0, d - bd)
-    final_d = prefix_d[:, s_pad - 1, :][:, None, :]
+    final_d = prefix_d[:, s_pad - 1:, :]         # [N, 1, R_TILE]
     clip_t = jnp.where(valid[None], final_d - excess_d, NEG_INF).max(axis=2)
 
     @pl.when(jt == 0)
@@ -175,32 +186,41 @@ def _fused_tick_kernel(
     # -- what-if family: `_whatif_kernel` per-step contributions --------
     w = w_ref[...].astype(jnp.float32)           # [N, S_pad, R_TILE]
     bw = bw_ref[...].astype(jnp.float32)
-    prefix_w = jnp.cumsum(w, axis=1)
+    prefix_w = _stage_prefix(w)
     excess_w = jnp.maximum(0.0, w - bw)
-    relp = relp_ref[...]                         # [N, S_pad]
-    rows = []
+    relp = relp_ref[0][:, :, None]               # [N, S_pad, 1]
+    # each stage row replays its own segment: row si of `arr` is
+    # relp[si] + (P[end] - P[start-1]), selected row by row
+    row = jax.lax.broadcasted_iota(jnp.int32, prefix_w.shape, 1)
+    arr = prefix_w
     for start, end in segments:
-        seg = prefix_w[:, end, :] - (prefix_w[:, start - 1, :] if start else 0.0)
+        seg = prefix_w[:, end:end + 1, :] - (
+            prefix_w[:, start - 1:start, :] if start else 0.0
+        )                                        # [N, 1, R_TILE]
         for si in range(start, min(end + 1, s_pad)):
-            rows.append(relp[:, si][:, None] + seg)
-    arr = jnp.stack(rows, axis=1)                # [N, S_pad, R_TILE]
-    amax = amax_ref[...][:, :, None]             # [N, S_pad, 1]
-    sec = sec_ref[...][:, :, None]
-    lead = lead_ref[...][:, :, None]
+            arr = jnp.where(row == si, relp[:, si:si + 1, :] + seg, arr)
+    amax = amax_ref[0][:, :, None]               # [N, S_pad, 1]
+    sec = sec_ref[0][:, :, None]
+    lead = lead_ref[0][:, :, None]
     other = jnp.where(gidx[None] == lead, sec, amax)
     new_a = jnp.maximum(other, arr - excess_w)
     contrib = jnp.where(valid[None], jnp.maximum(0.0, amax - new_a), 0.0)
 
-    zf = jnp.zeros((s_pad, r_tile), jnp.float32)
+    # the step folds unroll at trace time: a static step index is a static
+    # slice of the leading (untiled) axis, which Mosaic lowers; it has no
+    # dynamic slice of a value.  The adds keep step order.
+    wacc = jnp.zeros((s_pad, r_tile), jnp.float32)
     if with_regimes:
         # -- regime family: the `_regime_kernel` step fold, carrying the
-        # what-if accumulator in the same loop (one pass over the steps).
+        # what-if accumulator in the same pass over the steps.
         thr = thr_ref[0].astype(jnp.float32)
         zi = jnp.zeros((s_pad, r_tile), jnp.int32)
-
-        def body(t, carry):
-            count, onset, last, runs, streak, prev, sume, sumpfx, wacc = carry
-            e = jax.lax.dynamic_index_in_dim(excess_w, t, 0, keepdims=False)
+        count, onset, last, runs, streak, prev = (
+            zi, zi + _BIG_IDX, zi - 1, zi, zi, zi
+        )
+        sume = sumpfx = wacc
+        for t in range(n_steps):
+            e = excess_w[t]
             act = e > thr
             acti = act.astype(jnp.int32)
             count = count + acti
@@ -208,19 +228,12 @@ def _fused_tick_kernel(
             last = jnp.maximum(last, jnp.where(act, t, -1))
             runs = runs + acti * (1 - prev)
             streak = jnp.where(act, streak + 1, 0)
+            prev = acti
             # adds only (no multiply, so no FMA divergence from the
             # oracle): sum_t t*e recovers as n*sum_e - C in the epilog
             sume = sume + e
             sumpfx = sumpfx + sume
-            wacc = wacc + jax.lax.dynamic_index_in_dim(
-                contrib, t, 0, keepdims=False
-            )
-            return (count, onset, last, runs, streak, acti, sume, sumpfx, wacc)
-
-        init = (zi, zi + _BIG_IDX, zi - 1, zi, zi, zi, zf, zf, zf)
-        count, onset, last, runs, streak, _prev, sume, sumpfx, wacc = (
-            jax.lax.fori_loop(0, n_steps, body, init)
-        )
+            wacc = wacc + contrib[t]
         count_ref[0] = count
         onset_ref[0] = onset
         last_ref[0] = last
@@ -229,12 +242,8 @@ def _fused_tick_kernel(
         sume_ref[0] = sume
         sumpfx_ref[0] = sumpfx
     else:
-        def wbody(t, wacc):
-            return wacc + jax.lax.dynamic_index_in_dim(
-                contrib, t, 0, keepdims=False
-            )
-
-        wacc = jax.lax.fori_loop(0, n_steps, wbody, zf)
+        for t in range(n_steps):
+            wacc = wacc + contrib[t]
     wif_ref[0] = wacc
 
     # -- co-activation family: rank->host collapse inside the kernel ---
@@ -365,10 +374,14 @@ def _fused_tick_impl(
     bd, bw, bw_jrs = _fleet_baselines(
         d, w, baseline, need_jrs=with_regimes or with_hosts
     )
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
     if r_tile is None:
-        r_tile = min(_pad_to(r, _LANE), 512)
+        # the four window blocks are [N, S_pad, R_TILE] each, so a long
+        # window takes a narrower rank tile to fit scoped VMEM
+        r_tile = min(
+            _pad_to(r, _LANE), 512,
+            max(_LANE, _WINDOW_BLOCK_ELEMS // n // _LANE * _LANE),
+        )
     s_pad = _pad_to(s, _SUBLANE)
     r_pad = _pad_to(r, r_tile)
     pad = ((0, 0), (0, s_pad - s), (0, r_pad - r))
@@ -380,12 +393,16 @@ def _fused_tick_impl(
 
     segments = sync_segments(sync_stages, s, s_pad)
     wt = _sm(w)
-    amax, second, leader, relprev = _whatif_stats(wt, segments, r)
-    inputs = [_sm(d), _sm(bd), wt, _sm(bw), amax, second, leader, relprev]
+    # per-(step, stage) what-if stats as [J, N, S_pad]: a (1, N, S_pad)
+    # block spans the array's last two dims, legal for any window N
+    stats = [
+        x.reshape(jn, n, s_pad) for x in _whatif_stats(wt, segments, r)
+    ]
+    inputs = [_sm(d), _sm(bd), wt, _sm(bw), *stats]
 
     n_tiles = r_pad // r_tile
     win_spec = pl.BlockSpec((n, s_pad, r_tile), lambda job, t: (job, 0, t))
-    stat_spec = pl.BlockSpec((n, s_pad), lambda job, t: (job, 0))
+    stat_spec = pl.BlockSpec((1, n, s_pad), lambda job, t: (job, 0, 0))
     in_specs = [win_spec] * 4 + [stat_spec] * 4
     if with_regimes or with_hosts:
         # padded cells carry e = thr = 0, so they are never active
@@ -474,13 +491,6 @@ _STATIC = (
     "min_excess_s", "rel_excess", "r_tile", "interpret",
 )
 _fused_tick_jit = jax.jit(_fused_tick_impl, static_argnames=_STATIC)
-#: the service hot path's variant: the staged window tensor is donated,
-#: so on accelerator backends XLA may reuse its device buffer for kernel
-#: temporaries instead of holding both live (the staging arena itself is
-#: host memory and stays reusable — see `core.streaming.WindowStager`).
-_fused_tick_jit_donated = jax.jit(
-    _fused_tick_impl, static_argnames=_STATIC, donate_argnums=(0,)
-)
 
 
 def fused_fleet_tick(
@@ -495,7 +505,6 @@ def fused_fleet_tick(
     rel_excess: float = _REGIME_DEFAULTS.rel_excess,
     r_tile: int | None = None,
     interpret: bool | None = None,
-    donate: bool = False,
 ) -> FusedTickPacket:
     """All four per-tick analyses of d[J, N, R, S] in ONE Pallas dispatch.
 
@@ -510,10 +519,6 @@ def fused_fleet_tick(
       host_index: [J, R] i32 rank->host map (with `num_hosts`); enables
         the co-activation family.  None = family off.
       with_regimes: compute the regime-statistics family.
-      donate: donate the window tensor's device buffer to the dispatch
-        (the service hot path; only effective on accelerator backends —
-        CPU jit ignores donation, so the flag is dropped there to keep
-        the logs quiet).
 
     Returns a `FusedTickPacket` bit-exact against the four unfused
     routes on every field.
@@ -529,9 +534,7 @@ def fused_fleet_tick(
                 f"host_index must be [J, R]={d.shape[0], d.shape[2]}, "
                 f"got {host_index.shape}"
             )
-    use_donate = donate and jax.default_backend() in ("tpu", "gpu")
-    fn = _fused_tick_jit_donated if use_donate else _fused_tick_jit
-    return fn(
+    return _fused_tick_jit(
         d, baseline, host_index,
         sync_stages=sync_stages,
         num_hosts=int(num_hosts),

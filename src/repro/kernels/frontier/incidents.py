@@ -45,6 +45,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from .frontier import resolve_interpret
+
 __all__ = [
     "CoActivationPacket",
     "TierAxes",
@@ -57,13 +59,6 @@ __all__ = [
 
 _SUBLANE = 8
 _LANE = 128
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
 
 
 def _pad_to(x: int, m: int) -> int:
@@ -159,8 +154,7 @@ def _prep_activity(
     [J*N, S_pad, H_pad].  Padded cells carry 0 — never active."""
     jn, n, h, s = act.shape
     a = jnp.asarray(act).astype(jnp.int32)
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
     if h_tile is None:
         h_tile = min(_pad_to(h, _LANE), 512)
     s_pad = _pad_to(s, _SUBLANE)

@@ -2,7 +2,8 @@
 
 Accepts the natural [N, R, S] window layout, performs the one-time
 transpose/pad to the TPU-native [N, S_pad, R_pad] stage-major layout,
-dispatches the Pallas kernel (interpret=True automatically off-TPU), and
+dispatches the Pallas kernel (interpret mode only on the CPU backend:
+`frontier.resolve_interpret`), and
 post-processes the tiny [N, S] accumulators into the full evidence packet
 (advances, gap, Eq. 2 shares, Eq. 4 gains).
 """
@@ -17,6 +18,7 @@ import jax.numpy as jnp
 from .frontier import (
     frontier_window_kernel,
     regime_stats_kernel,
+    resolve_interpret,
     whatif_matrix_kernel,
 )
 from .ref import (
@@ -32,16 +34,10 @@ from ...core.regimes import RegimeParams as _RegimeParams
 
 _SUBLANE = 8
 _LANE = 128
+_F32_TINY = float(jnp.finfo(jnp.float32).tiny)
 #: regime-route threshold defaults come from the ONE definition in
 #: core.regimes — tuning RegimeParams retunes the kernel routes too.
 _REGIME_DEFAULTS = _RegimeParams()
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
 
 
 def _pad_to(x: int, m: int) -> int:
@@ -106,9 +102,17 @@ class FleetPacket(NamedTuple):
 
 
 def _fleet_median_baseline(d: jax.Array) -> jax.Array:
-    """Per-job cohort median baseline (cross-rank, cross-step, per-stage)."""
+    """Per-job cohort median baseline (cross-rank, cross-step, per-stage).
+
+    A subnormal median is flushed to zero here, as the CPU and the TPU
+    flush it when it is stored.  The explicit select also keeps XLA from
+    contracting the median's `0.5 * (lo + hi)` into a consumer's
+    subtraction as one FMA, whose exact subnormal result would then be
+    flushed instead: every route sees the same baseline value.
+    """
     jn, n, r, s = d.shape
     med = jnp.median(d.reshape(jn, n * r, s), axis=1)       # [J, S]
+    med = jnp.where(jnp.abs(med) < _F32_TINY, 0.0, med)
     return jnp.broadcast_to(med[:, None, None, :], d.shape)
 
 
@@ -130,8 +134,7 @@ def _prep_stage_major(
     if baseline is None:
         baseline = _fleet_median_baseline(d)
     baseline = jnp.broadcast_to(baseline.astype(jnp.float32), d.shape)
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
     if r_tile is None:
         r_tile = min(_pad_to(r, _LANE), 512)
     s_pad = _pad_to(s, _SUBLANE)
@@ -409,8 +412,7 @@ def fleet_regime_stats(
         )
     e = jnp.maximum(0.0, w - b_jrs[:, None])                 # [J, N, R, S]
     thr = jnp.maximum(min_excess_s, rel_excess * b_jrs)      # [J, R, S]
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = resolve_interpret(interpret)
     if r_tile is None:
         r_tile = min(_pad_to(r, _LANE), 512)
     s_pad = _pad_to(s, _SUBLANE)

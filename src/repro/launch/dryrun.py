@@ -1,14 +1,12 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 """Multi-pod dry-run: lower + compile every (architecture x shape x mesh)
 cell against the production meshes and extract roofline terms.
 
     PYTHONPATH=src python -m repro.launch.dryrun \
         --arch all --shape all --mesh both --out experiments/dryrun
 
-The two lines above MUST precede any other import (jax locks the device
-count at first init); this is the only entry point that forces 512 host
-devices.
+`main()` sets XLA_FLAGS to 512 host devices before anything initializes
+a JAX backend (jax fixes the device count then); this is the only entry
+point that forces them, and importing the module forces nothing.
 
 Per live cell this produces:
   - production-graph compile (scan-over-layers) -> memory_analysis proves
@@ -22,6 +20,7 @@ Per live cell this produces:
 import argparse
 import dataclasses
 import json
+import os
 import time
 import traceback
 
@@ -346,6 +345,7 @@ def run_cell(
 
 
 def main() -> None:
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     p = argparse.ArgumentParser(description=__doc__)
     p.add_argument("--arch", default="all")
     p.add_argument("--shape", default="all")

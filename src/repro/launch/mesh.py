@@ -24,18 +24,12 @@ HARDWARE = {
 }
 
 
-def _axis_type_kwargs(n_axes: int) -> dict:
-    """`axis_types=Auto` where supported; jax < 0.5 predates AxisType."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_local_mesh(data: int | None = None, model: int = 1):
@@ -44,7 +38,8 @@ def make_local_mesh(data: int | None = None, model: int = 1):
     if data is None:
         data = max(1, n // model)
     return jax.make_mesh(
-        (data, model), ("data", "model"), **_axis_type_kwargs(2)
+        (data, model), ("data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2,
     )
 
 

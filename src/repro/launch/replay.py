@@ -25,6 +25,7 @@ import json
 import sys
 
 from ..replay import generate_trace, load_trace, parse_trace, replay_trace
+from .compile_cache import use_compile_cache
 
 
 def make_argparser() -> argparse.ArgumentParser:
@@ -121,6 +122,7 @@ def run(args) -> dict:
 
 
 def main() -> None:
+    use_compile_cache()
     args = make_argparser().parse_args()
     out = run(args)
     text = json.dumps(out, indent=2)

@@ -46,6 +46,7 @@ from ..sim.scenarios import (
     hidden_rank_scenario,
 )
 from ..telemetry.packets import encode_packet, from_diagnosis
+from .compile_cache import use_compile_cache
 
 SYNC_PROFILES = {
     "ddp": DDP_SYNC,
@@ -339,6 +340,7 @@ def run(args) -> dict:
 
 
 def main() -> None:
+    use_compile_cache()
     args = make_argparser().parse_args()
     print(json.dumps(run(args), indent=2))
 

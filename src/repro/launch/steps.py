@@ -29,7 +29,7 @@ __all__ = [
     "build_train_step",
     "build_prefill_step",
     "build_serve_step",
-    "batch_shardings_for",
+    "batch_shardings",
 ]
 
 
@@ -39,13 +39,6 @@ class TrainState:
     params: Any
     opt: OptState
     step: jax.Array
-
-
-def batch_shardings_for(model: Model, mesh: Mesh, plan: ShardingPlan, specs: dict):
-    out = {}
-    for name, spec in specs.items():
-        out[name] = batch_sharding(mesh, len(spec.shape), plan)
-    return out
 
 
 _ATTN_CACHE_KEYS = {"k", "v", "cross_k", "cross_v"}
@@ -90,6 +83,22 @@ def cache_shardings_for(mesh: Mesh, plan: ShardingPlan, cache_specs: Any,
         return NamedSharding(mesh, PS(*dims))
 
     return jax.tree_util.tree_map_with_path(leaf, cache_specs)
+
+
+def batch_shardings(
+    mesh: Mesh, plan: ShardingPlan, batch_specs: dict, accum_steps: int = 1
+) -> dict:
+    """Shardings of a batch whose per-microbatch shapes are
+    `batch_specs`: the batch dim over the plan's DP axes.  With
+    `accum_steps > 1` the batch is host-shaped [accum, micro, ...] and the
+    leading accum dim is replicated."""
+    lead = (None,) if accum_steps > 1 else ()
+    return {
+        name: NamedSharding(
+            mesh, P(*lead, *batch_sharding(mesh, len(spec.shape), plan).spec)
+        )
+        for name, spec in batch_specs.items()
+    }
 
 
 def build_train_step(
@@ -170,20 +179,10 @@ def build_train_step(
         metrics = {"loss": loss, **om}
         return TrainState(params=params, opt=opt, step=state.step + 1), metrics
 
-    batch_sh = None
-    if batch_specs:
-        if accum_steps > 1:
-            # [accum, micro, ...] layout: leading accum dim replicated,
-            # micro batch dim sharded over the DP axes.
-            batch_sh = {
-                name: NamedSharding(
-                    mesh,
-                    P(None, *batch_sharding(mesh, len(spec.shape), plan).spec),
-                )
-                for name, spec in batch_specs.items()
-            }
-        else:
-            batch_sh = batch_shardings_for(model, mesh, plan, batch_specs)
+    batch_sh = (
+        batch_shardings(mesh, plan, batch_specs, accum_steps)
+        if batch_specs else None
+    )
     fn = jax.jit(
         train_step,
         in_shardings=(state_sh, batch_sh),
@@ -208,7 +207,7 @@ def build_prefill_step(
         return model.forward(params, batch, triangular=triangular)
 
     batch_sh = (
-        batch_shardings_for(model, mesh, plan, batch_specs) if batch_specs else None
+        batch_shardings(mesh, plan, batch_specs) if batch_specs else None
     )
     fn = jax.jit(
         prefill,

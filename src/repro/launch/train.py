@@ -32,8 +32,9 @@ from ..distributed.sharding import BASELINE_PLAN, ShardingPlan
 from ..models import build_model
 from ..optim.adamw import AdamWConfig
 from ..telemetry.collector import Monitor
+from .compile_cache import use_compile_cache
 from .mesh import make_local_mesh
-from .steps import TrainState, build_train_step, init_train_state
+from .steps import batch_shardings, build_train_step, init_train_state
 
 
 def make_argparser() -> argparse.ArgumentParser:
@@ -89,9 +90,19 @@ def run(args) -> dict:
 
     opt_cfg = AdamWConfig(peak_lr=args.lr, warmup_steps=max(10, args.steps // 20),
                           decay_steps=args.steps)
+    # the batch is split over the mesh's `data` axis: with `accum`,
+    # host-shaped [accum, micro, seq], one microbatch per scan step
+    micro = args.batch // args.accum
+    batch_specs = {
+        k: jax.ShapeDtypeStruct((micro, args.seq), jnp.int32)
+        for k in ("tokens", "labels")
+    }
+    batch_sh = batch_shardings(mesh, BASELINE_PLAN, batch_specs, args.accum)
+    host_shape = (args.accum, micro, args.seq) if args.accum > 1 else None
     with mesh:
         train_step, state_sh = build_train_step(
-            model, mesh, BASELINE_PLAN, opt_cfg, accum_steps=args.accum
+            model, mesh, BASELINE_PLAN, opt_cfg,
+            batch_specs=batch_specs, accum_steps=args.accum,
         )
         state = init_train_state(model, jax.random.PRNGKey(0))
 
@@ -110,17 +121,24 @@ def run(args) -> dict:
         pipeline = PrefetchPipeline(source, start_cursor=start, stall=stall)
 
         losses = []
+        step_seconds = []
         step_counter = {"i": start}
         prev_metrics = None
         t_train0 = time.perf_counter()
         try:
             for i in range(start, args.steps):
                 step_counter["i"] = i
+                t_step = time.perf_counter()
                 with monitor.step():
                     with monitor.stage("data.next_wait"):
                         # host staging is part of the data path: charged here
                         host_batch = next(pipeline)
-                        batch = {k: jnp.asarray(v) for k, v in host_batch.items()}
+                        if host_shape is not None:
+                            host_batch = {
+                                k: v.reshape(host_shape)
+                                for k, v in host_batch.items()
+                            }
+                        batch = jax.device_put(host_batch, batch_sh)
                     t_dispatch = time.perf_counter()
                     with monitor.stage("step.dispatch_cpu_wall"):
                         state, metrics = train_step(state, batch)
@@ -146,6 +164,7 @@ def run(args) -> dict:
                                 extra={"data": pipeline.state()},
                             )
                 monitor.end_of_step()
+                step_seconds.append(time.perf_counter() - t_step)
                 if profile_state["active_until"] == i:
                     jax.profiler.stop_trace()
                     profile_state["active_until"] = -1
@@ -168,6 +187,8 @@ def run(args) -> dict:
         "steps": args.steps - start,
         "first_loss": losses[0] if losses else None,
         "last_loss": losses[-1] if losses else None,
+        "losses": losses,
+        "step_seconds": step_seconds,
         "train_seconds": train_seconds,
         "monitor_overhead": monitor.overhead_fraction(train_seconds),
         "windows": [
@@ -185,6 +206,7 @@ def run(args) -> dict:
 
 
 def main() -> None:
+    use_compile_cache()
     args = make_argparser().parse_args()
     summary = run(args)
     print(json.dumps(summary, indent=2, default=str))

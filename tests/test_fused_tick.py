@@ -154,6 +154,28 @@ class TestFusedParityDegenerate:
         _assert_tick_equal(fused, four, context="empty four")
         _assert_tick_equal(fused, ref, context="empty ref")
 
+    def test_subnormal_median_baseline(self):
+        # the case hypothesis found: one step of one job is 1.0 on every
+        # rank of stage 1 except rank 4, which is FLT_MIN.  That stage's
+        # cohort median is 0.5 * (0 + FLT_MIN), a subnormal.  Unflushed,
+        # XLA:CPU fused the median's multiply into the four-dispatch
+        # route's `w - median` as one FMA and flushed the exact result
+        # FLT_MIN/2 to 0, while the fused route subtracted the stored
+        # (flushed) median and kept FLT_MIN.  The baseline now flushes a
+        # subnormal median to 0 itself, as the chip does, so every route
+        # sees the same value.
+        tiny = np.finfo(np.float32).tiny
+        d = np.zeros((3, 2, 9, 5), np.float32)
+        d[1, 1, :, 1] = 1.0
+        d[1, 1, 4, 1] = tiny
+        hosts = np.zeros((3, 9), np.int64)
+        fused, four, ref = _tick_all_three(
+            d, sync_stages=(), host_index=hosts, num_hosts=1
+        )
+        assert fused.regimes.sum_excess[1, 1, 4] == tiny
+        _assert_tick_equal(fused, four, context="subnormal median four")
+        _assert_tick_equal(fused, ref, context="subnormal median ref")
+
     def test_explicit_baseline(self):
         d = _window((2, 4, 5, 4), seed=5)
         # explicit cohort-shared per-stage reference ([S]: broadcastable
