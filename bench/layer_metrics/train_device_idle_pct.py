@@ -1,0 +1,9 @@
+"""Share of the traced train window in which no operation ran on the
+device: 1 minus the union of the `XLA Ops` intervals over the window,
+averaged over the chips."""
+
+
+def read(run: dict):
+    if run.get("driver") != "train":
+        return None
+    return 100.0 * run["trace"].idle_share
