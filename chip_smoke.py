@@ -221,6 +221,8 @@ def phase_train() -> dict:
         "median_step_s_last20": float(np.median(steps[-20:])),
         "first_step_s": steps[0], "wall_s": wall,
         "monitor_overhead": summary["monitor_overhead"],
+        "monitor_total_overhead": summary["monitor_total_overhead"],
+        "monitor_counters": summary["monitor_metrics"]["counters"],
     }
     say("train", **out)
     check(len(losses) == 30, f"{len(losses)} losses for 30 steps")

@@ -289,6 +289,8 @@ class FleetService:
         grouped by (window shape, sync profile) — the sync segmentation
         is a static kernel argument and must match within a batch.
         """
+        import jax
+
         from ..kernels.frontier import four_dispatch_tick, fused_fleet_tick
 
         use_fused = self.fused if fused is None else bool(fused)
@@ -314,8 +316,6 @@ class FleetService:
                     # this service's device so the dispatch runs there
                     # (same compiled program on every CPU device —
                     # bit-identical outputs, tests/test_sharded_fleet.py).
-                    import jax
-
                     stacked = jax.device_put(stacked, self.device)
             with self._phase("tick.kernel"):
                 if use_fused:
@@ -327,6 +327,9 @@ class FleetService:
                     tick = four_dispatch_tick(
                         stacked, sync_stages=sync_idx, with_regimes=False,
                     )
+                # the device's time is the kernel's: wait for it here, so
+                # that `tick.epilog` times host work only
+                jax.block_until_ready(tick)
             with self._phase("tick.epilog"):
                 pkt, wif = tick.frontier, tick.whatif
                 shares = np.asarray(pkt.shares)[:j_live]   # [J, S]

@@ -191,6 +191,8 @@ def run(args) -> dict:
         "step_seconds": step_seconds,
         "train_seconds": train_seconds,
         "monitor_overhead": monitor.overhead_fraction(train_seconds),
+        "monitor_total_overhead": monitor.total_overhead_fraction(train_seconds),
+        "monitor_metrics": monitor.metrics.as_dict(),
         "windows": [
             {
                 "index": r.window_index,

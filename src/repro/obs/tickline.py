@@ -64,7 +64,7 @@ __all__ = [
 TICK_PHASES: tuple[str, ...] = (
     "tick.decode",          # wire decode (FleetIngest)
     "tick.stage",           # window staging + device placement
-    "tick.kernel",          # fused / four-dispatch kernel dispatch
+    "tick.kernel",          # fused / four-dispatch kernel: dispatch + device wait
     "tick.epilog",          # kernel outputs -> per-job registry state
     "tick.regimes",         # streaming folds, eviction, activity build
     "tick.correlate",       # incident engine observe / cross-shard reduce
