@@ -206,8 +206,12 @@ def fleet_tensor(seed: int = 0, shape=(64, 20, 128, 6)):
 
 
 def phase_train() -> dict:
+    from repro.configs import get_config
     from repro.launch import train
+    from repro.models.attention import attention_path
 
+    cfg = get_config("paper-gpt-125m")
+    path = attention_path((8, 1024, cfg.n_heads, cfg.head_dim), cfg.n_kv_heads, None)
     t0 = time.perf_counter()
     summary = train.run(train.make_argparser().parse_args(TRAIN_ARGS))
     wall = time.perf_counter() - t0
@@ -219,7 +223,7 @@ def phase_train() -> dict:
         "steps": len(losses), "windows_labelled": len(labelled),
         "labels": [w["labels"] for w in labelled],
         "median_step_s_last20": float(np.median(steps[-20:])),
-        "first_step_s": steps[0], "wall_s": wall,
+        "first_step_s": steps[0], "wall_s": wall, "attention_path": path,
         "monitor_overhead": summary["monitor_overhead"],
         "monitor_total_overhead": summary["monitor_total_overhead"],
         "monitor_counters": summary["monitor_metrics"]["counters"],

@@ -1,16 +1,28 @@
-"""Attention: chunked online-softmax (memory-roofline-safe), sliding-window
-banded form, and single-token KV-cache decode.
+"""Attention: causal prefill (a Pallas flash kernel on the TPU, else a
+chunked online softmax in XLA), bidirectional cross attention, and
+single-token KV-cache decode.
+
+`attention_path` is the one rule for which causal implementation runs.
+On a single TPU device, for multi-head attention (no GQA, no sliding
+window) at a sequence length that is a multiple of 128, it is JAX's
+Pallas flash-attention kernel (`repro.kernels.flash`): score tiles stay in
+VMEM, tiles above the diagonal are skipped, and the kernel's own VJP
+recomputes probabilities tile by tile.  It takes q, k and v in the
+activations' dtype (the configuration's compute dtype) and accumulates in
+f32, so `cast_f32`, `remat_qblock`, the chunk sizes and `triangular` have
+no effect there.  Everything else (the CPU, GQA, sliding windows,
+unaligned lengths, the dry run's unrolled cost extraction on the CPU,
+and decode) runs the XLA path below.
 
 GQA is computed *grouped* (no `jnp.repeat` materialization): queries are
 reshaped to [B, S, KV, G, D] and contracted against the un-expanded KV, so
 HBM traffic for KV stays at the true GQA size — this matters for the decode
 roofline where KV-cache reads dominate.
 
-Prefill uses a double-chunked online-softmax (lax.scan over KV chunks inside
-a scan over Q chunks): peak scores memory is q_chunk x kv_chunk instead of
-S^2.  With ``triangular=True`` the Q-chunk loop is unrolled with exact KV
-ranges, skipping fully-masked KV chunks (the causal-FLOPs hillclimb lever —
-see EXPERIMENTS.md §Perf).
+The XLA prefill is a double-chunked online softmax (lax.scan over KV
+chunks inside a scan over Q chunks): peak scores memory is
+q_chunk x kv_chunk instead of S^2.  With ``triangular=True`` the Q-chunk
+loop is unrolled with exact KV ranges, skipping fully-masked KV chunks.
 """
 from __future__ import annotations
 
@@ -18,6 +30,8 @@ import functools
 
 import jax
 import jax.numpy as jnp
+
+from ..kernels.flash import flash_causal_attention
 
 NEG = -1e30
 
@@ -74,6 +88,35 @@ def _finish(m, l, acc, b, sq, h, d, dtype):
     return out.transpose(0, 3, 1, 2, 4).reshape(b, sq, h, d).astype(dtype)
 
 
+def _one_tpu() -> bool:
+    """Whether a computation traced now lands on a TPU, in a process that
+    sees one device.  The platform is the default device's where one is
+    set (`jax.default_device`), else the default backend's.  Mosaic
+    kernels cannot be partitioned by GSPMD, so a multi-device process
+    keeps the XLA path."""
+    dev = jax.config.jax_default_device
+    if dev is None:
+        platform = jax.default_backend()
+    else:
+        platform = dev if isinstance(dev, str) else dev.platform
+    return platform == "tpu" and jax.device_count() == 1
+
+
+def attention_path(q_shape: tuple, n_kv: int, window: int | None) -> str:
+    """"flash" where `chunked_causal_attention` runs the Pallas kernel,
+    else "xla".  q_shape: [B, S, H, D]."""
+    _, s, h, d = q_shape
+    if (
+        window is None
+        and n_kv == h
+        and s % 128 == 0
+        and (d <= 128 or d % 128 == 0)
+        and _one_tpu()
+    ):
+        return "flash"
+    return "xla"
+
+
 def chunked_causal_attention(
     q: jax.Array,
     k: jax.Array,
@@ -90,8 +133,12 @@ def chunked_causal_attention(
     """Causal (optionally sliding-window) attention, O(q_chunk*kv_chunk) memory.
 
     q: [B, S, H, D]; k, v: [B, S, KV, D].  S must divide by the chunk sizes
-    (configs guarantee this; smoke tests use small aligned chunks).
+    (configs guarantee this; smoke tests use small aligned chunks).  Where
+    `attention_path` says "flash", the keyword arguments other than
+    `window` have no effect.
     """
+    if attention_path(q.shape, k.shape[2], window) == "flash":
+        return flash_causal_attention(q, k, v)
     b, s, h, d = q.shape
     n_kv = k.shape[2]
     scale = 1.0 / (d**0.5)

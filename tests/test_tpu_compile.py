@@ -18,6 +18,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels.flash import flash_causal_attention
 from repro.kernels.frontier import (
     TierAxes,
     co_activation,
@@ -102,3 +103,16 @@ def test_tiered_co_activation(one_chip):
         lambda a: tiered_co_activation(a, tiers, interpret=False), act
     )
     assert "tpu_custom_call" in text
+
+
+def test_flash_attention_forward_and_backward(one_chip):
+    # paper-gpt-125m's attention at its cell's shape (B 8, S 1024, H 12,
+    # D 64) with the committed tile sizes, under `jax.grad`: the forward
+    # with residuals and both backward kernels (dkv, dq)
+    qkv = jax.ShapeDtypeStruct((8, 1024, 12, 64), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        return flash_causal_attention(q, k, v).astype(jnp.float32).sum()
+
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv)
+    assert text.count("tpu_custom_call") >= 3
