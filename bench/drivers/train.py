@@ -6,8 +6,30 @@ the same loop as the window, and records what the comparison needs:
 the first three losses, the first gradient's leaf norms (from AdamW's
 first moment after one step) and the leaf norms of the parameters'
 change after three steps.  The window then times steps on the host
-clock.  After the window the program's state is freed and the plain
-reference (`gpt_reference`) repeats the first three steps in float32.
+clock, keeping each step's `metrics` as the device arrays the step
+returned; they are fetched once the window has closed.  After the window
+the program's state is freed and the plain reference repeats the first
+three steps in float32.
+
+Everything that belongs to one model is in the configuration's
+`reference` module, `bench/<reference>.py`, loaded from that file.  It
+imports nothing of the program and gives:
+
+- `program_fields(model)`: the program's `ModelConfig` fields for the
+  configuration's `model` section;
+- `key_for(seed)`, `init_params(key, model, dtype)` and
+  `make_params(key, *, model, dtype)` (`model` the section's sorted
+  items): the weights, in the tree the program's `model.init` makes;
+- `Tokens(vocab_size, batch, seq, seed)` with `batch_at(cursor)`: the
+  seeded rows, `{"tokens", "labels"}`;
+- `train_step(params, mu, nu, count, tokens, labels, *, model, opt, rows,
+  precision)`: one AdamW step in float32, `precision="fp8"` the control;
+  returns (params, mu, nu, count, loss, clipped gradient);
+- `leaf_norms(tree)`: each leaf's norm by its path;
+- `train_flops_per_token(model, seq)`: model FLOPs of one training token.
+
+The loop, the comparison, the control and the result are this file's and
+shared by every train configuration.
 
 The loop follows `repro.launch.train.run` line for line (its Monitor
 stages, in its order, the previous step's loss fetched after this step's
@@ -25,16 +47,15 @@ import statistics
 import time
 
 import harness
-import flops
-import gpt_reference as ref
 
 LEAF_FLOOR = 1e-3   # leaves whose reference gradient is below this share of the median leaf's
 REF_ROWS = 2        # rows of a batch the reference takes at a time, so that it fits
 WARMUP_STEPS = 10   # steps in set-up, the first three of them compared with the reference
 
 
-def program(config: dict, traffic: dict):
-    """The program's model, compiled step and Monitor for this cell."""
+def program(ref, config: dict, traffic: dict):
+    """The program's model, compiled step and Monitor for this cell, whose
+    reference module is `ref`."""
     import jax
     import jax.numpy as jnp
 
@@ -47,13 +68,9 @@ def program(config: dict, traffic: dict):
     from repro.optim.adamw import AdamWConfig
     from repro.telemetry.collector import Monitor
 
-    m, seq = config["model"], traffic["seq"]
+    seq = traffic["seq"]
     cfg = dataclasses.replace(
-        get_config(config["program_config"]),
-        n_layers=m["n_layer"], d_model=m["n_embd"], n_heads=m["n_head"],
-        n_kv_heads=m["n_head"], head_dim=m["n_embd"] // m["n_head"],
-        d_ff=m["n_inner"], vocab_size=m["vocab_size"],
-        rope_theta=m["rope_theta"],
+        get_config(config["program_config"]), **ref.program_fields(config["model"]),
         param_dtype=config["dtypes"]["param"], compute_dtype=config["dtypes"]["compute"],
     )
     cfg = dataclasses.replace(
@@ -78,7 +95,7 @@ def program(config: dict, traffic: dict):
     return model, mesh, monitor, step, state_sh, batch_sh
 
 
-def make_state(model, state_sh, config: dict, seed: int):
+def make_state(ref, model, state_sh, config: dict, seed: int):
     """The initial weights, made on the device in one call, and the train
     state built from them."""
     import jax
@@ -95,8 +112,8 @@ def make_state(model, state_sh, config: dict, seed: int):
         (a.shape, a.dtype) != (b.shape, b.dtype)
         for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(have))
     ):
-        raise harness.BenchError("the program's parameter tree differs from "
-                                 "the benchmark's GPT layout")
+        raise harness.BenchError("the program's parameter tree differs from the "
+                                 f"layout of bench/{config['reference']}.py")
     p0 = ref.make_params(ref.key_for(seed), model=tuple(sorted(dims.items())), dtype=dtype)
     state = jax.jit(
         # a copy: the step donates the state, and p0 must outlive it
@@ -118,6 +135,8 @@ class Loop:
         self.pipeline, self.batch_sh = pipeline, batch_sh
         self.prev = None
         self.losses: list[float] = []
+        #: each step's `metrics` as the step returned them, on the device
+        self.metrics: list[dict] = []
         self.step_seconds: list[float] = []
         #: per step: batch fetch, dispatch, loss fetch, Monitor (seconds)
         self.phases: list[tuple] = []
@@ -136,6 +155,7 @@ class Loop:
             with monitor.stage("step.dispatch_cpu_wall"):
                 with ann("bench.step_dispatch"):
                     self.state, metrics = self.step(self.state, batch)
+                    self.metrics.append(metrics)
             monitor.observe_output(metrics["loss"], (time.perf_counter() - t_dispatch) * 1e3)
             t_fetch = time.perf_counter()
             with monitor.stage("step.device_wait_cpu_wall"):
@@ -190,12 +210,11 @@ class HostWatch:
                 "involuntary_switches": self.usage1.ru_nivcsw - self.usage0.ru_nivcsw}
 
 
-def first_steps(loop: Loop, config: dict, p0) -> dict:
+def first_steps(ref, loop: Loop, config: dict, p0) -> dict:
     """Three steps through the window's own loop, recording the program's
     losses, first-gradient leaf norms and parameter-change leaf norms
     (against the initial weights `p0`)."""
     import jax
-    import jax.numpy as jnp
 
     b1 = config["optimizer"]["b1"]
     losses = []
@@ -207,11 +226,11 @@ def first_steps(loop: Loop, config: dict, p0) -> dict:
             grad = jax.device_get(jax.jit(
                 lambda mu: {k: v / (1 - b1) for k, v in ref.leaf_norms(mu).items()}
             )(loop.state.opt.mu))
-    change = jax.device_get(change_norms(loop.state.params, p0))
+    change = jax.device_get(change_norms(ref, loop.state.params, p0))
     return {"losses": losses, "grad": grad, "change": change}
 
 
-def change_norms(params, p0):
+def change_norms(ref, params, p0):
     import jax
     import jax.numpy as jnp
 
@@ -219,7 +238,7 @@ def change_norms(params, p0):
         lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32), p, q)))(params, p0)
 
 
-def reference_steps(config: dict, traffic: dict, seed: int, precision: str = "f32",
+def reference_steps(ref, config: dict, traffic: dict, seed: int, precision: str = "f32",
                     rows_kept: int | None = None) -> dict:
     """The reference's first three steps on the same weights and rows.
     `rows_kept` plants the half-batch fault: the mean over the first
@@ -248,7 +267,7 @@ def reference_steps(config: dict, traffic: dict, seed: int, precision: str = "f3
         losses.append(float(loss))
         if i == 0:
             grad = jax.device_get(ref.leaf_norms(g))
-    change = jax.device_get(change_norms(params, p0))
+    change = jax.device_get(change_norms(ref, params, p0))
     return {"losses": losses, "grad": grad, "change": change}
 
 
@@ -279,21 +298,23 @@ def run(ctx) -> dict:
     from repro.data.pipeline import PrefetchPipeline
 
     config, traffic, seed = ctx.config, ctx.traffic, ctx.seed
-    model, mesh, monitor, step, state_sh, batch_sh = program(config, traffic)
+    ref = harness.reference(config["reference"])
+    model, mesh, monitor, step, state_sh, batch_sh = program(ref, config, traffic)
     source = ref.Tokens(config["model"]["vocab_size"], traffic["batch"], traffic["seq"], seed)
     pipeline = PrefetchPipeline(source)
     try:
         with mesh:
-            state, p0 = make_state(model, state_sh, config, seed)
+            state, p0 = make_state(ref, model, state_sh, config, seed)
             loop = Loop(step, state, monitor, pipeline, batch_sh, ctx.trace)
             del state
-            got = first_steps(loop, config, p0)
+            got = first_steps(ref, loop, config, p0)
             del p0
             for _ in range(WARMUP_STEPS - 3):
                 loop.one()
             loop.drain()
             jax.block_until_ready(loop.state)
             loop.losses.clear()
+            loop.metrics.clear()
             loop.step_seconds.clear()
             loop.phases.clear()
             setup_s = ctx.clock.now()
@@ -314,6 +335,7 @@ def run(ctx) -> dict:
             overhead = (monitor.monitor_path_seconds - mon0) / wall
             window_losses = list(loop.losses)
             window_steps = list(loop.step_seconds)
+            step_metrics = jax.device_get(loop.metrics)
             slowest = window_steps.index(max(window_steps))
             slowest_phases = loop.phases[slowest]
             labelled = sum(1 for r in monitor.aggregator.reports if r.diagnosis.labels)
@@ -322,7 +344,7 @@ def run(ctx) -> dict:
     memory = harness.memory_peak(ctx.devices)
     del loop
     gc.collect()
-    want = reference_steps(config, traffic, seed)
+    want = reference_steps(ref, config, traffic, seed)
     readings = compare(got, want)
     tokens = steps * traffic["batch"] * traffic["seq"]
     harness.say("train", steps=steps, window_s=wall, compiles_in_window=in_window,
@@ -347,8 +369,10 @@ def run(ctx) -> dict:
         "memory_peak_bytes": memory,
         "layer": {
             "tokens_per_s": tokens / wall,
-            "flops_per_token": flops.gpt_train_flops_per_token(config["model"], traffic["seq"]),
+            "flops_per_token": ref.train_flops_per_token(config["model"], traffic["seq"]),
             "monitor_overhead_fraction": overhead,
+            "step_metrics": {k: [m[k].tolist() for m in step_metrics]
+                             for k in step_metrics[0] if k != "loss"},
         },
     }
 
@@ -358,9 +382,10 @@ def control(ctx) -> dict:
     put in the program's place against the float32 reference: the
     reference with float8 products, and the reference's mean over the
     first half of the rows."""
-    want = reference_steps(ctx.config, ctx.traffic, ctx.seed)
-    low = reference_steps(ctx.config, ctx.traffic, ctx.seed, precision="fp8")
-    half = reference_steps(ctx.config, ctx.traffic, ctx.seed,
+    ref = harness.reference(ctx.config["reference"])
+    want = reference_steps(ref, ctx.config, ctx.traffic, ctx.seed)
+    low = reference_steps(ref, ctx.config, ctx.traffic, ctx.seed, precision="fp8")
+    half = reference_steps(ref, ctx.config, ctx.traffic, ctx.seed,
                            rows_kept=ctx.traffic["batch"] // 2)
     drop = lambda r: {k: v for k, v in r.items() if k != "leaves_left_out"}
     return {"control_fp8": drop(compare(low, want)),

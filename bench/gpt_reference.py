@@ -10,6 +10,9 @@ program is handed the same tree), layers stacked on a leading axis.
 `precision="fp8"` is the control: every matrix product's operands are
 rounded to float8 with one scale per tensor (e4m3 forward, e5m2 for the
 cotangents backward), the step below the configuration's bfloat16.
+
+A configuration names this module as its `reference`; the functions the
+`train` driver calls are listed in its docstring.
 """
 from __future__ import annotations
 
@@ -19,7 +22,28 @@ import math
 import jax
 import jax.numpy as jnp
 
+import flops
+
 HIGHEST = jax.lax.Precision.HIGHEST
+
+
+# -- the configuration in the program's terms ------------------------------------
+
+
+def program_fields(model: dict) -> dict:
+    """The program's `ModelConfig` fields for nanoGPT's keys: one key and
+    value head per query head, heads of `n_embd / n_head`."""
+    return {
+        "n_layers": model["n_layer"], "d_model": model["n_embd"],
+        "n_heads": model["n_head"], "n_kv_heads": model["n_head"],
+        "head_dim": model["n_embd"] // model["n_head"], "d_ff": model["n_inner"],
+        "vocab_size": model["vocab_size"], "rope_theta": model["rope_theta"],
+    }
+
+
+def train_flops_per_token(model: dict, seq: int) -> float:
+    """Model FLOPs of one training token (`flops.gpt_train_flops_per_token`)."""
+    return flops.gpt_train_flops_per_token(model, seq)
 
 
 # -- weights and tokens from the seed ------------------------------------------

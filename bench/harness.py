@@ -1,11 +1,23 @@
 """What every cell shares: finding a cell's files by name, the device
 checks, the compile counter, the traced window and the result line.
 
-A cell is one entry of `workloads` in BENCHMARK.json.  Its configuration
-is `bench/configs/<config>.json`, whose `driver` names
-`bench/drivers/<driver>.py`; its traffic is `bench/traffic/<traffic>.json`;
-each per-layer metric is read by `bench/layer_metrics/<metric>.py`.  A
-later change adds a configuration, a traffic mix or a metric by adding
+A cell is one entry of `workloads` in BENCHMARK.json.  Its files:
+
+- `bench/configs/<config>.json`, the configuration as it is run; its
+  `driver` names `bench/drivers/<driver>.py`, and a `train`
+  configuration's `reference` names `bench/<reference>.py`, the plain
+  reference of its model, with the functions `drivers/train.py` lists;
+- `bench/traffic/<traffic>.json`, the traffic mix;
+- `bench/layer_metrics/<metric>.py` for each per-layer metric, whose
+  `read(run)` takes the driver's `layer` readings (a train run's
+  `step_metrics`, each non-loss key of the step's `metrics` by step, among
+  them) and the trace summary (`trace_reduce.TraceSummary`, with `op_s`,
+  the device time of every op by name), and returns None where it finds
+  nothing to read;
+- `tests/bench/tiny/<config>.json`, the configuration's cut to CPU size
+  for the benchmark's own tests.
+
+A later change adds a configuration, a traffic mix or a metric by adding
 such files and entries: nothing here lists them.
 """
 from __future__ import annotations
@@ -48,18 +60,35 @@ def load_json(kind: str, name: str, bench: Path | None = None) -> dict:
     return json.loads(path.read_text())
 
 
+def _exec(path: Path, module_name: str):
+    """The file `path` run as a module named `module_name`."""
+    spec = importlib.util.spec_from_file_location(module_name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _module_name(*parts: str) -> str:
+    return "_".join(["bench", *parts]).replace(".", "_").replace("-", "_")
+
+
 def load_module(kind: str, name: str, bench: Path | None = None):
     """`bench/<kind>/<name>.py` as a module (names may hold dots)."""
     bench = bench or BENCH
     path = bench / kind / f"{name}.py"
     if not path.is_file():
         raise BenchError(f"no {kind} module {path.relative_to(bench.parent)}")
-    spec = importlib.util.spec_from_file_location(
-        f"bench_{kind}_{name.replace('.', '_').replace('-', '_')}", path
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return _exec(path, _module_name(kind, name))
+
+
+def reference(name: str, bench: Path | None = None):
+    """The plain reference module `bench/<name>.py` that a configuration
+    names, loaded from that file."""
+    bench = bench or BENCH
+    path = bench / f"{name}.py"
+    if not path.is_file():
+        raise BenchError(f"no reference module {path.relative_to(bench.parent)}")
+    return _exec(path, _module_name("reference", name))
 
 
 def cell_metrics(cell: str, root: Path | None = None) -> tuple[list[dict], list[dict]]:

@@ -1,6 +1,7 @@
-"""Model FLOP/s utilisation of the train step: model FLOPs per token
-(`flops.gpt_train_flops_per_token`, no recomputation) times the traced
-window's tokens per second, over the chips' bf16 peak."""
+"""Model FLOP/s utilisation of the train step: model FLOPs per token (the
+configuration's reference module's `train_flops_per_token`, no
+recomputation) times the traced window's tokens per second, over the
+chips' bf16 peak."""
 
 
 def read(run: dict):

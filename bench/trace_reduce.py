@@ -1,6 +1,7 @@
 """From a profiler trace (`.xplane.pb`) to device busy and idle time,
-device time per compiled module, the heaviest device operations and the
-longest idle gaps, each gap named by the host annotation it fell in.
+device time per compiled module and per operation, the heaviest device
+operations and the longest idle gaps, each gap named by the host
+annotation it fell in.
 
 Device planes are `/device:TPU:<n>`; their `XLA Ops` line holds one event
 per operation run on the chip, their `XLA Modules` line one per program
@@ -30,6 +31,7 @@ class TraceSummary:
     busy_s: float                     # mean over devices of the busy union
     module_s: dict = field(default_factory=dict)    # module -> device s (sum over devices)
     module_calls: dict = field(default_factory=dict)
+    op_s: dict = field(default_factory=dict)        # op -> device s (sum over devices)
     top_ops: list = field(default_factory=list)     # [[op, seconds]]
     idle_gaps: list = field(default_factory=list)   # [[host annotation, seconds]]
     idle_by_label: dict = field(default_factory=dict)
@@ -148,6 +150,7 @@ def reduce_trace(path: str, *, top: int = 10) -> TraceSummary:
         busy_s=busy_total / len(devices),
         module_s=module_s,
         module_calls=module_calls,
+        op_s=op_s,
         top_ops=sorted(([k, v] for k, v in op_s.items()), key=lambda x: -x[1])[:top],
         idle_gaps=named[:top],
         idle_by_label=idle_by_label,

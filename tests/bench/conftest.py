@@ -1,5 +1,9 @@
 """Shared set-up for the benchmark's own tests: `bench/` and `src/` on the
-path, and a run driven on the CPU at a size a test can hold."""
+path, and a run driven on the CPU at a size a test can hold.
+
+Each configuration's CPU size is `tests/bench/tiny/<config>.json`: its
+`config` keys replace the configuration's, its `traffic` keys the cell's
+traffic's, and its `limits` the configuration's limits."""
 from __future__ import annotations
 
 import copy
@@ -17,11 +21,49 @@ for p in (ROOT / "bench", ROOT / "src"):
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
+TINY = "tests/bench/tiny"
+
+
+def tiny_file(config: str, root: Path = ROOT) -> dict:
+    """`config`'s cut to CPU size; fails, naming the file to add, where
+    there is none."""
+    path = root / TINY / f"{config}.json"
+    if not path.is_file():
+        pytest.fail(f"configuration {config!r} has no CPU size: add {TINY}/{config}.json "
+                    "with the `config`, `traffic` and `limits` keys a test run takes")
+    return json.loads(path.read_text())
+
+
+_GPT = tiny_file("paper-gpt-125m")
+_FLEET = tiny_file("fleet-ddp128")
+
 #: paper-gpt-125m's shapes cut to CPU size (widths too: a test, not a cell)
-TINY_GPT = {"n_layer": 2, "n_head": 4, "n_embd": 128, "n_inner": 512,
-            "vocab_size": 512, "block_size": 64, "bias": True,
-            "rope_theta": 10000.0, "ln_eps": 1e-6}
-TINY_TRAIN_TRAFFIC = {"batch": 4, "seq": 64, "monitor_window": 4}
+TINY_GPT = _GPT["config"]["model"]
+TINY_TRAIN_TRAFFIC = _GPT["traffic"]
+
+
+def cut_to_cpu(config: dict, traffic: dict, cut: dict) -> tuple[dict, dict]:
+    """A configuration and its traffic at the CPU size `cut` (a tiny file)."""
+    return ({**config, **cut["config"], "limits": cut["limits"]},
+            {**traffic, **cut["traffic"]})
+
+
+def tiny_cell(cell: str, root: Path = ROOT) -> tuple[dict, dict]:
+    """The configuration and the traffic of `cell` at their CPU size."""
+    import harness
+
+    w, bench = harness.workload(cell, root), root / "bench"
+    return cut_to_cpu(harness.load_json("configs", w["config"], bench),
+                      harness.load_json("traffic", w["traffic"], bench),
+                      tiny_file(w["config"], root))
+
+
+def train_cells(root: Path = ROOT) -> list[str]:
+    """The cells in BENCHMARK.json whose configuration runs the `train` driver."""
+    import harness
+
+    return [w["name"] for w in harness.manifest(root)["workloads"]
+            if harness.load_json("configs", w["config"], root / "bench")["driver"] == "train"]
 
 
 def tiny_train_config() -> dict:
@@ -46,14 +88,9 @@ def cpu_context(config: dict, traffic: dict, *, seed: int = 7, seconds: float = 
     )
 
 
-@pytest.fixture
-def tiny_train():
-    return tiny_train_config(), dict(TINY_TRAIN_TRAFFIC)
-
-
 #: fleet-ddp128's shapes cut to CPU size (the Pallas tick runs interpreted)
-TINY_FLEET = {"jobs": 9, "ranks": 8, "sample_jobs": 6}
-TINY_FLEET_TRAFFIC = {"rate_windows_per_s": 30, "route_k": 10}
+TINY_FLEET = _FLEET["config"]
+TINY_FLEET_TRAFFIC = _FLEET["traffic"]
 
 #: the fleet cells as their manifest entries will read: driver, configurations and
 #: traffic are in `bench/`; they enter BENCHMARK.json once their bounds are measured
@@ -70,64 +107,64 @@ FLEET_METRICS = [
      "source": "host_clock"},
 ]
 
-#: limits for the CPU-sized runs, from CPU readings at these sizes on
-#: seeds 21-23 (the cells' own limits are set from chip readings at the
-#: cells' sizes).  The bf16 program against the reference read loss
-#: 0.00066-0.00078, gradient 0.00075-0.00126, change 0.0023-0.0075; the
-#: float8 control's gradient 0.011-0.017; the half-batch fault's loss
-#: 0.016-0.029, gradient 0.16-0.18, change 0.068-0.083.  The kernel reads
-#: 2e-7 against the oracle, the bfloat16 oracle 1.0.
-TINY_LIMITS = {
-    "train": {"loss_gap": 0.005, "grad_gap": 0.005, "change_gap": 0.04},
-    "fleet": {"kernel_gap": 1e-4, "leader_mismatch": 0, "route_misses": 0},
-}
+#: limits for the CPU-sized runs of paper-gpt-125m and fleet-ddp128, by
+#: driver, from CPU readings at these sizes (each tiny file's `why`); the
+#: cells' own limits are set from chip readings at the cells' sizes, and
+#: each configuration's CPU limits are its tiny file's (`tiny_cell`)
+TINY_LIMITS = {"train": _GPT["limits"], "fleet": _FLEET["limits"]}
 
 
-@pytest.fixture
-def tiny_bench(tmp_path, monkeypatch):
-    """A copy of the benchmark whose cells run at CPU size, with the
-    harness pointed at it and its look for a chip skipped: returns a
-    function that runs one cell through `run.measure` and gives back the
-    result object."""
+def make_tiny_bench(src: Path, dst: Path, monkeypatch):
+    """Copy the benchmark at `src` to `dst` with every cell cut to its
+    configuration's CPU size (`src`'s tiny files), point the harness at
+    the copy and skip its look for a chip: returns a function that runs
+    one cell through `run.measure` and gives back the result object.  A
+    cell whose configuration has no tiny file fails when it is run."""
     import shutil
 
     import harness
     import run
 
-    shutil.copytree(ROOT / "bench", tmp_path / "bench",
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    shutil.copy(ROOT / "BENCHMARK.json", tmp_path / "BENCHMARK.json")
-    bench = tmp_path / "bench"
-    m = json.loads((tmp_path / "BENCHMARK.json").read_text())
+    shutil.copytree(src / "bench", dst / "bench", ignore=shutil.ignore_patterns("__pycache__"))
+    bench = dst / "bench"
+    m = json.loads((src / "BENCHMARK.json").read_text())
     missing = [c for c in FLEET_CELLS if c["name"] not in {w["name"] for w in m["workloads"]}]
     if missing:
         m["workloads"] += missing
         m["end_to_end"] += [dict(e, workloads=[c["name"] for c in missing])
                             for e in FLEET_METRICS]
-        (tmp_path / "BENCHMARK.json").write_text(json.dumps(m))
+    (dst / "BENCHMARK.json").write_text(json.dumps(m))
+    uncut = set()
     for w in m["workloads"]:
+        if not (src / TINY / f"{w['config']}.json").is_file():
+            uncut.add(w["name"])
+            continue
         cfg_path = bench / "configs" / f"{w['config']}.json"
         traffic_path = bench / "traffic" / f"{w['traffic']}.json"
-        cfg = json.loads(cfg_path.read_text())
-        if cfg["driver"] == "train":
-            cfg["model"] = dict(TINY_GPT)
-            traffic = dict(TINY_TRAIN_TRAFFIC)
-        else:
-            cfg.update(TINY_FLEET)
-            traffic = dict(TINY_FLEET_TRAFFIC)
-        cfg["limits"] = dict(TINY_LIMITS[cfg["driver"]])
+        cfg, traffic = cut_to_cpu(json.loads(cfg_path.read_text()),
+                                  json.loads(traffic_path.read_text()),
+                                  tiny_file(w["config"], src))
         cfg_path.write_text(json.dumps(cfg))
         traffic_path.write_text(json.dumps(traffic))
-    monkeypatch.setattr(harness, "ROOT", tmp_path)
+    monkeypatch.setattr(harness, "ROOT", dst)
     monkeypatch.setattr(harness, "BENCH", bench)
     monkeypatch.setattr(harness, "use_compile_cache", lambda jax: "")
+    monkeypatch.syspath_prepend(str(bench))
 
     def measure(workload, *, seed=5, seconds=1.0, trace=0):
         import jax
 
+        if workload in uncut:
+            tiny_file(harness.workload(workload)["config"], src)
         args = run.parse(["--workload", workload, "--seed", str(seed),
                           "--seconds", str(seconds), "--trace", str(trace)])
         return run.measure(args, devices=jax.devices()[:1],
                            peak={"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9})
 
     return measure
+
+
+@pytest.fixture
+def tiny_bench(tmp_path, monkeypatch):
+    """The benchmark at CPU size (`make_tiny_bench` of this checkout)."""
+    return make_tiny_bench(ROOT, tmp_path, monkeypatch)

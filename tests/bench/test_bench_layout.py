@@ -1,12 +1,16 @@
 """The harness finds cells, configurations, traffic and per-layer metrics
-by name: adding files and entries adds them, with no harness file edited."""
+by name: adding files and entries adds them, with no file of the
+benchmark or of its tests edited."""
 import json
 import shutil
 import subprocess
 import sys
 
+import pytest
+
 import harness
-from conftest import ROOT
+from conftest import ROOT, make_tiny_bench, train_cells
+from test_bench_readers import check_reader, reader_drivers
 
 
 def copy_bench(tmp_path):
@@ -23,31 +27,114 @@ def test_listing_covers_every_cell_and_reader():
     assert set(out["readers"]) == {e["name"] for e in m["per_layer"]}
 
 
-def test_a_cell_and_a_metric_are_added_by_files_alone(tmp_path):
-    root = copy_bench(tmp_path)
-    before = {p.relative_to(root): p.read_bytes() for p in (root / "bench").rglob("*")
-              if p.is_file()}
-    (root / "bench/configs/gpt-dummy.json").write_text(json.dumps({"driver": "train"}))
-    (root / "bench/traffic/dummy-mix.json").write_text(json.dumps({"batch": 1}))
-    (root / "bench/layer_metrics/dummy.share.py").write_text(
-        "def read(run):\n    return None\n")
+TWIN = "gpt-twin"            # the second train configuration
+TWIN_CELL = "train-twin-b8s512"
+TWIN_READER = "twin.grad_norm"   # a reader name may hold dots
+
+
+def add_second_train_config(root, *, tiny: bool) -> None:
+    """Add to the benchmark at `root`, by new files and entries alone, a
+    second train configuration with its own reference module (a copy of
+    `gpt_reference` under another name, at other widths), its traffic,
+    its CPU size (where `tiny`), its cell in the train metrics'
+    `workloads`, and a reader of one `step_metrics` key with its sample."""
+    bench = root / "bench"
+    shutil.copy(bench / "gpt_reference.py", bench / "twin_reference.py")
+    cfg = json.loads((bench / "configs/paper-gpt-125m.json").read_text())
+    cfg["reference"] = "twin_reference"
+    cfg["model"].update(n_layer=24, n_head=16, n_embd=1024, n_inner=4096)
+    (bench / "configs" / f"{TWIN}.json").write_text(json.dumps(cfg))
+    (bench / "traffic/twin-b8s512.json").write_text(
+        json.dumps({"batch": 8, "seq": 512, "monitor_window": 10}))
+    if tiny:
+        (root / "tests/bench/tiny" / f"{TWIN}.json").write_text(json.dumps({
+            "config": {"model": {**cfg["model"], "n_layer": 3, "n_head": 2, "n_embd": 64,
+                                 "n_inner": 256, "vocab_size": 384, "block_size": 32}},
+            "traffic": {"batch": 4, "seq": 32, "monitor_window": 4},
+            "limits": {"loss_gap": 0.005, "grad_gap": 0.005, "change_gap": 0.04}}))
+    (bench / "layer_metrics" / f"{TWIN_READER}.py").write_text(
+        "import statistics\n\n\n"
+        "def read(run):\n"
+        "    norms = run.get(\"step_metrics\", {}).get(\"grad_norm\")\n"
+        "    if run.get(\"driver\") != \"train\" or not norms:\n"
+        "        return None\n"
+        "    return float(statistics.median(norms))\n")
+    (root / "tests/bench/data/layer_inputs" / f"{TWIN_READER}.json").write_text(
+        json.dumps({"step_metrics": {"grad_norm": [0.9, 0.8, 0.85]}}))
     m = json.loads((root / "BENCHMARK.json").read_text())
-    m["workloads"].append({"name": "dummy-cell", "config": "gpt-dummy",
-                           "traffic": "dummy-mix", "chips": 1, "why": "a test"})
-    m["per_layer"].append({"name": "dummy.share", "unit": "%", "better": "higher",
-                           "source": "device_trace", "layer": "device",
-                           "moves": "tokens_per_s", "workloads": ["dummy-cell"]})
-    m["end_to_end"][0]["workloads"].append("dummy-cell")
+    m["configs"].append({"name": TWIN, "source": "https://huggingface.co/gpt2-medium",
+                         "file": f"bench/configs/{TWIN}.json", "reduced": [], "why": "a test"})
+    m["workloads"].append({"name": TWIN_CELL, "config": TWIN, "traffic": "twin-b8s512",
+                           "chips": 1, "why": "a test"})
+    for e in m["end_to_end"] + m["per_layer"]:
+        if e["name"] in ("tokens_per_s", "step_p95_ms", "train_mfu_pct",
+                         "train_device_idle_pct"):
+            e["workloads"].append(TWIN_CELL)
+    m["per_layer"].append({"name": TWIN_READER, "unit": "1", "better": "lower",
+                           "source": "program_counter", "layer": "train step",
+                           "moves": "tokens_per_s", "workloads": [TWIN_CELL]})
     (root / "BENCHMARK.json").write_text(json.dumps(m))
+
+
+def fake_trace(monkeypatch):
+    """The CPU's profiler trace has no TPU plane: hand the readers a summary."""
+    import trace_reduce
+
+    summary = trace_reduce.TraceSummary(window_s=1.0, devices=1, busy_s=0.5,
+                                        op_s={"fusion": 0.4})
+    monkeypatch.setattr(trace_reduce, "find_xplane", lambda trace_dir: trace_dir)
+    monkeypatch.setattr(trace_reduce, "reduce_trace", lambda path: summary)
+
+
+@pytest.mark.parametrize("tiny", [True, False], ids=["cpu_size", "no_cpu_size"])
+def test_a_cell_and_a_metric_are_added_by_files_alone(tmp_path, monkeypatch, tiny):
+    root = tmp_path / "src"
+    copy_bench(root)
+    shutil.copytree(ROOT / "tests/bench", root / "tests/bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    (root / "tests/bench/data/layer_inputs").mkdir(exist_ok=True)
+    before = {p.relative_to(root): p.read_bytes()
+              for d in ("bench", "tests/bench") for p in (root / d).rglob("*") if p.is_file()}
+    add_second_train_config(root, tiny=tiny)
     out = harness.listing(root)
-    assert out["cells"]["dummy-cell"]["driver"] == "train"
-    assert out["cells"]["dummy-cell"]["per_layer"] == ["dummy.share"]
-    assert "dummy.share" in out["readers"]
-    after = {p.relative_to(root): p.read_bytes() for p in (root / "bench").rglob("*")
-             if p.is_file() and p.relative_to(root) in before}
+    assert out["cells"][TWIN_CELL]["driver"] == "train"
+    assert out["cells"][TWIN_CELL]["end_to_end"] == ["tokens_per_s", "step_p95_ms", "setup_s"]
+    assert out["cells"][TWIN_CELL]["per_layer"] == [
+        "train_mfu_pct", "train_device_idle_pct", TWIN_READER]
+    assert TWIN_READER in out["readers"]
+    assert harness.load_module("layer_metrics", TWIN_READER, root / "bench").__name__ == (
+        "bench_layer_metrics_twin_grad_norm")
+    assert TWIN_CELL in train_cells(root)   # the train fault and control tests run it
+    drivers = dict(reader_drivers(root))
+    assert drivers[TWIN_READER] == {"train"}
+    for name, readers_drivers in drivers.items():
+        check_reader(name, readers_drivers, root)
+    measure = make_tiny_bench(root, tmp_path / "run", monkeypatch)
+    if tiny:
+        got = measure(TWIN_CELL, seed=2**31 + 17)
+        assert got["correct"], got["checks"]
+        assert set(got["metrics"]) == {"tokens_per_s", "step_p95_ms", "setup_s"}
+        fake_trace(monkeypatch)
+        got = measure(TWIN_CELL, seed=2**31 + 18, trace=1)
+        assert got["correct"], got["checks"]
+        assert set(got["metrics"]) == {"train_mfu_pct", "train_device_idle_pct", TWIN_READER}
+        assert got["metrics"][TWIN_READER]["value"] > 0.0
+    else:
+        with pytest.raises(pytest.fail.Exception, match=f"add tests/bench/tiny/{TWIN}.json"):
+            measure(TWIN_CELL)
+    after = {p: (root / p).read_bytes() for p in before}
     assert after == before
-    reader = harness.load_module("layer_metrics", "dummy.share", root / "bench")
-    assert reader.read({}) is None
+
+
+def test_a_missing_reference_module_is_named(tmp_path):
+    with pytest.raises(harness.BenchError, match="bench/no_such_reference.py"):
+        harness.reference("no_such_reference")
+    ref = harness.reference("gpt_reference")
+    assert ref.__file__ == str(ROOT / "bench/gpt_reference.py") and ref.train_flops_per_token
+    # a name that another module on the path already has still loads the named file
+    (tmp_path / "bench").mkdir()
+    (tmp_path / "bench/trace_reduce.py").write_text("NAMED = True\n")
+    assert harness.reference("trace_reduce", tmp_path / "bench").NAMED
 
 
 def test_without_a_tpu_there_is_no_result(tmp_path):

@@ -19,7 +19,7 @@ def test_gpt_reference_matches_the_program_model_in_float32():
     config = tiny_train_config()
     config["dtypes"] = {"param": "float32", "compute": "float32", "optimizer_state": "float32"}
     train = harness.load_module("drivers", "train")
-    model = train.program(config, TINY_TRAIN_TRAFFIC)[0]
+    model = train.program(ref, config, TINY_TRAIN_TRAFFIC)[0]
     dims = config["model"]
     params = ref.init_params(ref.key_for(2**33 + 5), dims, jnp.float32)
     batch = ref.Tokens(dims["vocab_size"], 4, 32, seed=3).batch_at(0)

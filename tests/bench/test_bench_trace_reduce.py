@@ -33,6 +33,16 @@ def test_device_time_per_module(summary):
     assert sum(s for _, s in summary.top_ops) >= summary.busy_s * 0.5
 
 
+def test_device_time_of_every_op(summary):
+    # the heaviest ops are the head of the whole table, which readers of an
+    # op outside them read
+    assert summary.top_ops == sorted(([k, v] for k, v in summary.op_s.items()),
+                                     key=lambda x: -x[1])[:10]
+    assert len(summary.op_s) >= len(summary.top_ops) and all(
+        v > 0 for v in summary.op_s.values())
+    assert sum(summary.op_s.values()) >= summary.busy_s
+
+
 def test_longest_gap_is_named_by_the_host_annotation(summary):
     label, seconds = summary.idle_gaps[0]
     assert label == "bench.sleep" and seconds >= 0.003
